@@ -669,8 +669,9 @@ class FLRoundEngine:
                 params, *batched)
 
         def _augment(key, x, y, m, aplan):
-            return augmentation.online_augment_batch(key, x, y, m, aplan,
-                                                     impl=cfg.warp_impl)
+            with jax.named_scope("alg2_warp"):
+                return augmentation.online_augment_batch(key, x, y, m, aplan,
+                                                         impl=cfg.warp_impl)
 
         if self._tp_rows:
             # under TP rows the model axis stays compiler-auto, and XLA
@@ -707,23 +708,29 @@ class FLRoundEngine:
                 update = _updates_for(merged)
             else:
                 update = base_update
-            xs, ys, ms_raw = store.slot_data(data, plan)
+
+            def local_train(p, x, y, m, k):
+                with jax.named_scope("local_train"):
+                    return update(p, x, y, m, k)
+
+            with jax.named_scope("slot_gather"):
+                xs, ys, ms_raw = store.slot_data(data, plan)
             if parallel_clients:
                 ms = ms_raw[:, 0] * slot[:, :1]
-                row_fn = update
+                row_fn = local_train
                 weights = ms.sum(axis=1)
                 if use_aug:
                     (aplan,) = aug
                     def row_fn(p, x, y, m, k):           # noqa: F811
                         ax, ay = _aug_one(k, x, y, m, aplan)
-                        return update(p, ax, ay, m, k)
+                        return local_train(p, ax, ay, m, k)
                     # Eq. 6 over the expected post-augmentation sizes
                     weights = (ms * (1.0 + aplan.astype(jnp.float32)[ys[:, 0]])
                                ).sum(axis=1)
                 outs = _rows(row_fn, state, xs[:, 0], ys[:, 0], ms, keys)
                 return outs, weights
             ms = ms_raw * slot[..., None]
-            row_fn = update
+            row_fn = local_train
             weights = ms.sum(axis=(1, 2))
             if use_aug:
                 (aplan,) = aug
@@ -734,7 +741,7 @@ class FLRoundEngine:
                     ax, ay = jax.vmap(
                         lambda kk, x, y, m: _augment(kk, x, y, m, aplan)
                     )(aks, xr, yr, mr)
-                    return update(p, ax, ay, mr, k)
+                    return local_train(p, ax, ay, mr, k)
                 weights = (ms * (1.0 + aplan.astype(jnp.float32)[ys])
                            ).sum(axis=(1, 2))
             outs = _rows(row_fn, state, xs, ys, ms, keys)
@@ -791,8 +798,10 @@ class FLRoundEngine:
             state, extra = _prep(state, extra)          # §8: model gather
             stacked, weights = trained_rows(state, data, plan, unperm, slot,
                                             keys, *extra)
-            agg = self._aggregate(stacked, weights)
-            return self._fold(state, agg)
+            with jax.named_scope("eq6"):
+                agg = self._aggregate(stacked, weights)
+            with jax.named_scope("fold"):
+                return self._fold(state, agg)
 
         def wave_fn(state, data, plan, unperm, slot, keys, *extra):
             # the wave-partitioned entry point (core/async_engine.py): the
@@ -891,8 +900,10 @@ class FLRoundEngine:
         cfg = self.cfg
         if cfg.schedule == "kld":
             meds = scheduling.reschedule(self._counts[sel], cfg.gamma,
-                                         use_kernel=cfg.reschedule_kernel)
-            self.last_schedule_stats = scheduling.schedule_stats(meds)
+                                         use_kernel=cfg.reschedule_kernel,
+                                         telemetry=self.telemetry)
+            self.last_schedule_stats = scheduling.schedule_stats(
+                meds, self.telemetry)
             return [[int(sel[i]) for i in m.clients] for m in meds]
         if cfg.schedule == "random":
             if cfg.gamma == 1:      # FedAvg: selection order, one client each
@@ -901,7 +912,8 @@ class FLRoundEngine:
             meds = scheduling.random_schedule(len(sel), cfg.gamma,
                                               self._counts[sel],
                                               seed=cfg.seed + self._round)
-            self.last_schedule_stats = scheduling.schedule_stats(meds)
+            self.last_schedule_stats = scheduling.schedule_stats(
+                meds, self.telemetry)
             return [[int(sel[i]) for i in m.clients] for m in meds]
         raise ValueError(f"unknown schedule {cfg.schedule!r}")
 
@@ -1031,15 +1043,17 @@ class FLRoundEngine:
         wan0 = self.comm.total_bytes
         with tel.span("round", round=self._round, cohort=c,
                       schedule=cfg.schedule, policy=cfg.store) as rsp:
+            reads0 = tel.host_reads
             data_args, plan_args, unperm, slot, row_to_group, m_real = \
                 self.ensure_schedule()
-            keys = self._round_keys(row_to_group, m_real)
-            with tel.span("aggregate", mediators=m_real) as asp:
+            with tel.span("round_keys"):
+                keys = self._round_keys(row_to_group, m_real)
+            with tel.span("round_program", mediators=m_real) as psp:
                 self.server_state = self._round_fn(self.server_state,
                                                    data_args, plan_args,
                                                    unperm, slot, keys,
                                                    *self.extra_args())
-                asp.sync_on(self.server_state)
+                psp.sync_on(self.server_state)
             if cfg.aggregate == "weights":
                 self.comm.fedavg_round(c)
             else:
@@ -1063,7 +1077,8 @@ class FLRoundEngine:
             self.comm.end_round()
             self._round += 1
             rsp.set(wan_bytes=self.comm.total_bytes - wan0,
-                    traces=self.num_round_traces)
+                    traces=self.num_round_traces,
+                    host_reads=tel.host_reads - reads0)
         tel.observe_round(self, duration_s=rsp.duration_s)
 
     def fit(self, rounds: int, eval_every: int = 10) -> list[dict]:

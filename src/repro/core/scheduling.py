@@ -45,6 +45,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import distribution as dist
+from repro.obs.telemetry import as_telemetry, host_read
 
 
 @dataclass
@@ -53,19 +54,23 @@ class Mediator:
     clients: list[int] = field(default_factory=list)
     counts: np.ndarray | None = None
 
-    def kld_to_uniform(self) -> float:
-        return float(dist.kld_to_uniform(jnp.asarray(self.counts)))
+    def kld_to_uniform(self, telemetry=None) -> float:
+        return host_read(telemetry, "kld", float,
+                         dist.kld_to_uniform(jnp.asarray(self.counts)))
 
 
 def _score_candidates(mediator_counts: np.ndarray, candidate_counts: np.ndarray,
-                      *, use_kernel: bool = False) -> np.ndarray:
+                      *, use_kernel: bool = False,
+                      telemetry=None) -> np.ndarray:
     """D_KL(normalize(P_m + P_k) || U) for every candidate k."""
+    med = jnp.asarray(mediator_counts, jnp.float32)
+    cand = jnp.asarray(candidate_counts, jnp.float32)
     if use_kernel:
         from repro.kernels import ops as kops
-        return np.asarray(kops.kld_score(jnp.asarray(mediator_counts, jnp.float32),
-                                         jnp.asarray(candidate_counts, jnp.float32)))
-    return np.asarray(dist.merged_kld_scores(jnp.asarray(mediator_counts, jnp.float32),
-                                             jnp.asarray(candidate_counts, jnp.float32)))
+        scores = kops.kld_score(med, cand)
+    else:
+        scores = dist.merged_kld_scores(med, cand)
+    return host_read(telemetry, "alg3_scores", np.asarray, scores)
 
 
 @partial(jax.jit, static_argnames="gamma")
@@ -94,7 +99,8 @@ def _greedy_picks(counts_f32: jnp.ndarray, gamma: int) -> jnp.ndarray:
 
 
 def reschedule(client_counts: np.ndarray, gamma: int, *,
-               use_kernel: bool = False, impl: str = "auto") -> list[Mediator]:
+               use_kernel: bool = False, impl: str = "auto",
+               telemetry=None) -> list[Mediator]:
     """Alg. 3: partition clients into mediators of size <= gamma.
 
     Args:
@@ -108,6 +114,8 @@ def reschedule(client_counts: np.ndarray, gamma: int, *,
       impl: ``"batched"`` (device-resident; one executable dispatch per
         reschedule), ``"loop"`` (numpy greedy oracle), or ``"auto"``
         (batched). All produce identical mediator lists.
+      telemetry: the handle whose ``host_read`` spans name the device->host
+        reads (the picks, or the loop's per-step scores); off by default.
 
     Returns:
       List of ``Mediator``; every client appears in exactly one.
@@ -124,9 +132,10 @@ def reschedule(client_counts: np.ndarray, gamma: int, *,
         counts_f32 = jnp.asarray(client_counts, jnp.float32)
         if use_kernel:
             from repro.kernels import ops as kops
-            picks = np.asarray(kops.kld_greedy_picks(counts_f32, int(gamma)))
+            picks = kops.kld_greedy_picks(counts_f32, int(gamma))
         else:
-            picks = np.asarray(_greedy_picks(counts_f32, int(gamma)))
+            picks = _greedy_picks(counts_f32, int(gamma))
+        picks = host_read(telemetry, "alg3_picks", np.asarray, picks)
         return [Mediator(clients=[int(c) for c in picks[s:s + gamma]],
                          counts=client_counts[picks[s:s + gamma]].sum(0))
                 for s in range(0, num_clients, gamma)]
@@ -136,7 +145,8 @@ def reschedule(client_counts: np.ndarray, gamma: int, *,
         med = Mediator(counts=np.zeros(num_classes))
         while unassigned and len(med.clients) < gamma:
             cand = client_counts[unassigned]                      # (k, C)
-            scores = _score_candidates(med.counts, cand, use_kernel=use_kernel)
+            scores = _score_candidates(med.counts, cand, use_kernel=use_kernel,
+                                       telemetry=telemetry)
             best = int(np.argmin(scores))
             cid = unassigned.pop(best)
             med.clients.append(cid)
@@ -273,7 +283,8 @@ def partition_waves(durations: np.ndarray, wave_size: int
     return waves, stats
 
 
-def schedule_stats(mediators: list[Mediator]) -> dict[str, float]:
+def schedule_stats(mediators: list[Mediator],
+                   telemetry=None) -> dict[str, float]:
     """Fig. 7 metrics: distribution of D_KL(P_m || P_u) over mediators.
 
     These keys are an observability surface, not just a return value: the
@@ -281,9 +292,13 @@ def schedule_stats(mediators: list[Mediator]) -> dict[str, float]:
     placement stats merged under a disjoint ``store_`` prefix) and the
     telemetry layer republishes each one as an ``astraea_schedule_<key>``
     / ``astraea_store_<key>`` gauge every round -- renaming a key here
-    renames the exported metric.
+    renames the exported metric. Each mediator's KLD is one device->host
+    read (``host_read.kld``), all inside the ``schedule_stats`` span of
+    ``telemetry``.
     """
-    klds = np.array([m.kld_to_uniform() for m in mediators])
+    telemetry = as_telemetry(telemetry)
+    with telemetry.span("schedule_stats", mediators=len(mediators)):
+        klds = np.array([m.kld_to_uniform(telemetry) for m in mediators])
     return {
         "kld_mean": float(klds.mean()),
         "kld_median": float(np.median(klds)),

@@ -119,5 +119,6 @@ def resample(images: jax.Array, coords: jax.Array, *,
             dimension_semantics=("parallel", "parallel")),
         cost_estimate=cost_estimate(b, h, w, c, images.dtype.itemsize),
         interpret=interpret,
+        name="affine_warp",
     )(coords, planar)
     return jnp.transpose(out[:, :, :hw], (0, 2, 1)).reshape(b, h, w, c)

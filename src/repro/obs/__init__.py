@@ -6,14 +6,13 @@ metric names, and the off-by-default / zero-retrace contract.
 from repro.obs.metrics import (Counter, Gauge, Histogram,  # noqa: F401
                                MetricsRegistry)
 from repro.obs.telemetry import (NULL_TELEMETRY, NullTelemetry,  # noqa: F401
-                                 Telemetry, as_telemetry)
+                                 Telemetry, as_telemetry, host_read)
 from repro.obs.trace import (SCHEMA_VERSION, Span, Tracer,  # noqa: F401
-                             load_jsonl, start_device_trace,
-                             stop_device_trace, validate_events)
+                             load_jsonl, validate_events)
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "NULL_TELEMETRY", "NullTelemetry", "Telemetry", "as_telemetry",
-    "SCHEMA_VERSION", "Span", "Tracer", "load_jsonl",
-    "start_device_trace", "stop_device_trace", "validate_events",
+    "host_read", "SCHEMA_VERSION", "Span", "Tracer", "load_jsonl",
+    "validate_events",
 ]

@@ -15,37 +15,37 @@ measurement surfaces once per round:
   retrace *reasons* (anything past the first trace per entry point);
 * the async engine's staleness distribution, wave timings and commits.
 
-**Off by default, and off means zero.** ``as_telemetry(None)`` returns
-``NULL_TELEMETRY``, whose spans are a reused no-op context manager and
-whose observe hooks return immediately: no clock reads, no
-``block_until_ready``, no attribute formatting. Nothing in this module
-runs inside jit, so telemetry on-vs-off is bitwise identical in
-trajectories and adds zero round traces -- the invariant pinned by
-``tests/test_telemetry.py``.
+**Off by default, and off means annotation only.** ``as_telemetry(None)``
+returns ``NULL_TELEMETRY``, whose spans open the ``astraea.<span>``
+profiler annotation and nothing else, and whose observe hooks return
+immediately: no clock reads, no ``block_until_ready``, no attribute
+formatting, nothing recorded. Nothing in this module runs inside jit, so
+telemetry on-vs-off is bitwise identical in trajectories and adds zero
+round traces -- the invariant pinned by ``tests/test_telemetry.py``.
+
+``host_read`` is the one way the round path reads a device value to the
+host: it names the read on the profiler's clock (``host_read.<what>``)
+and, under a recording ``Telemetry``, counts it.
 """
 from __future__ import annotations
 
 import os
-from typing import Any
+from typing import Any, Callable
+
+from jax.profiler import TraceAnnotation
 
 from repro.obs.metrics import MetricsRegistry
-from repro.obs.trace import Tracer
+from repro.obs.trace import ANNOTATION_PREFIX, Tracer
 
 #: histogram bucket layouts (documented in obs/README.md)
 STALENESS_BUCKETS = (0, 1, 2, 4, 8)
 SECONDS_BUCKETS = (0.001, 0.01, 0.1, 1.0, 10.0, 60.0)
 
 
-class _NullSpan:
-    """Reused no-op span: the telemetry-off fast path."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return None
+class _NullSpan(TraceAnnotation):
+    """The telemetry-off span: the ``astraea.<span>`` profiler annotation
+    (a no-op TraceMe outside a profiler session) with the ``Span``
+    surface, recording nothing."""
 
     def set(self, **attrs):
         return self
@@ -56,18 +56,16 @@ class _NullSpan:
     duration_s = 0.0
 
 
-_NULL_SPAN = _NullSpan()
-
-
 class NullTelemetry:
     """Do-nothing stand-in carrying the full ``Telemetry`` surface."""
 
     enabled = False
     tracer = None
     metrics = None
+    host_reads = 0
 
     def span(self, name, **attrs):
-        return _NULL_SPAN
+        return _NullSpan(ANNOTATION_PREFIX + name)
 
     def instant(self, name, **attrs):
         return None
@@ -93,23 +91,35 @@ def as_telemetry(handle) -> "Telemetry | NullTelemetry":
     return handle
 
 
+def host_read(telemetry, what: str, read: Callable, value) -> Any:
+    """``read(value)`` -- ``np.asarray`` or ``float`` of a device value --
+    inside a ``host_read.<what>`` span, counted in ``host_reads`` under a
+    recording ``Telemetry``. The value is exactly ``read(value)``."""
+    telemetry = as_telemetry(telemetry)
+    with telemetry.span("host_read." + what):
+        out = read(value)
+    if telemetry.enabled:
+        telemetry.host_reads += 1
+    return out
+
+
 class Telemetry:
     """Enabled telemetry: host-side spans + per-round metric absorption.
 
     ``trace_dir`` (optional) is where ``flush()`` writes the artifacts:
     ``events.jsonl``, ``trace.json`` (Chrome/Perfetto), ``metrics.jsonl``
     (per-round timeline) and ``metrics.prom`` (Prometheus text).
-    ``profile=True`` turns on the ``jax.profiler.TraceAnnotation``
-    pass-through; ``clock`` is injectable for deterministic tests.
+    ``clock`` is injectable for deterministic tests. ``host_reads`` counts
+    the device->host reads made through ``host_read``.
     """
 
     enabled = True
 
-    def __init__(self, trace_dir: str | None = None, *,
-                 profile: bool = False, clock=None):
+    def __init__(self, trace_dir: str | None = None, *, clock=None):
         self.trace_dir = trace_dir
-        self.tracer = Tracer(clock=clock, profile=profile)
+        self.tracer = Tracer(clock=clock)
         self.metrics = MetricsRegistry()
+        self.host_reads = 0
         self._absorbed_commits = 0
 
     # ---- tracing passthrough ----
@@ -161,6 +171,9 @@ class Telemetry:
         m.counter("astraea_schedule_packs_total",
                   "host schedule packing events"
                   ).set_total(engine.num_schedule_packs)
+        m.counter("astraea_host_reads_total",
+                  "device->host reads on the round path (obs.host_read)"
+                  ).set_total(self.host_reads)
         retraces = [t for t in getattr(engine, "trace_log", [])
                     if t["reason"] != "initial"]
         m.gauge("astraea_unexpected_retraces",

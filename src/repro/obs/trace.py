@@ -3,10 +3,16 @@
 A ``Tracer`` records a tree of **spans** -- named wall-clock intervals
 with attributes -- plus zero-duration **instant** events, all on the
 host, entirely outside jit. The engine opens spans around its host-side
-phases (``round`` > ``reschedule``/``plan_refresh``/``pack`` >
-``store_stream``, async ``wave``/``commit``, sync ``aggregate``) so a
-round's wall-clock has one navigable timeline instead of being smeared
-across ad-hoc prints and bench JSONs.
+phases (``round`` > ``reschedule`` > ``host_read.alg3_picks`` /
+``schedule_stats``, ``pack`` > ``store_stream``, ``round_keys``,
+``round_program``; async ``wave``/``commit``) so a round's wall-clock has
+one navigable timeline instead of being smeared across ad-hoc prints and
+bench JSONs.
+
+Every span, recorded or not, is also a ``jax.profiler.TraceAnnotation``
+named ``astraea.<span>``: under a profiler session the host phases land on
+the device trace's clock, and without one the annotation is a TraceMe
+that does nothing (``obs/README.md``).
 
 Two export formats, both derived from the same event list:
 
@@ -24,13 +30,6 @@ values explicitly registered via ``Span.sync_on`` and only at span close
 clock is read) but NOTHING is blocked on when tracing is off: the no-op
 telemetry path (``obs.telemetry.NULL_TELEMETRY``) never touches a device
 value, which is what keeps telemetry-off rounds free of extra syncs.
-
-Optional ``jax.profiler`` pass-through: ``Tracer(profile=True)`` wraps
-every span in ``jax.profiler.TraceAnnotation`` so XLA device traces line
-up with the host spans, and ``start_device_trace``/``stop_device_trace``
-bracket a run with ``jax.profiler.start_trace``. A profiler that cannot
-start raises: a device trace that was asked for and silently not taken
-would read as a run in which the device did nothing.
 """
 from __future__ import annotations
 
@@ -38,8 +37,13 @@ import json
 import time
 from typing import Any, Callable
 
+from jax.profiler import TraceAnnotation
+
 #: bump when the JSONL event schema changes shape (validators pin this)
 SCHEMA_VERSION = 1
+
+#: prefix of every span's profiler annotation (``astraea.round``, ...)
+ANNOTATION_PREFIX = "astraea."
 
 #: keys every JSONL event must carry
 EVENT_KEYS = ("schema", "kind", "id", "parent", "name", "ts_us", "dur_us",
@@ -58,7 +62,7 @@ class Span:
         self.span_id, self.parent_id = span_id, parent_id
         self._tracer = tracer
         self._sync: list[Any] = []
-        self._annotation = None
+        self._annotation = TraceAnnotation(ANNOTATION_PREFIX + name)
         self.t0 = self.t1 = 0.0
 
     def set(self, **attrs) -> "Span":
@@ -79,10 +83,7 @@ class Span:
 
     def __enter__(self) -> "Span":
         tracer = self._tracer
-        if tracer.profile:
-            self._annotation = tracer._annotate(self.name)
-            if self._annotation is not None:
-                self._annotation.__enter__()
+        self._annotation.__enter__()
         tracer._stack.append(self.span_id)
         self.t0 = tracer.clock()
         return self
@@ -96,9 +97,7 @@ class Span:
         assert tracer._stack and tracer._stack[-1] == self.span_id, \
             "span close out of order (spans must nest)"
         tracer._stack.pop()
-        if self._annotation is not None:
-            self._annotation.__exit__(exc_type, exc, tb)
-            self._annotation = None
+        self._annotation.__exit__(exc_type, exc, tb)
         tracer._emit(self)
 
 
@@ -106,14 +105,11 @@ class Tracer:
     """Append-only span/instant recorder with JSONL + Chrome-trace export.
 
     ``clock`` defaults to ``time.perf_counter`` (monotonic); tests inject
-    a fake clock for deterministic timestamps. ``profile=True`` turns on
-    the ``jax.profiler.TraceAnnotation`` pass-through.
+    a fake clock for deterministic timestamps.
     """
 
-    def __init__(self, *, clock: Callable[[], float] | None = None,
-                 profile: bool = False):
+    def __init__(self, *, clock: Callable[[], float] | None = None):
         self.clock = clock or time.perf_counter
-        self.profile = profile
         self.events: list[dict] = []
         self._stack: list[int] = []
         self._next_id = 0
@@ -146,13 +142,6 @@ class Tracer:
                 "ts_us": (t0 - self._epoch) * 1e6,
                 "dur_us": (t1 - t0) * 1e6,
                 "attrs": _jsonable(attrs)}
-
-    def _annotate(self, name: str):
-        try:
-            import jax.profiler
-            return jax.profiler.TraceAnnotation(name)
-        except Exception:        # profiler unavailable: host spans only
-            return None
 
     # ---- export ----
     def to_jsonl(self) -> str:
@@ -243,16 +232,3 @@ def load_jsonl(path: str) -> list[dict]:
     """Parse an ``events.jsonl`` file back into the event list."""
     with open(path) as f:
         return [json.loads(line) for line in f if line.strip()]
-
-
-# ---- optional XLA device-trace pass-through ----
-def start_device_trace(log_dir: str) -> None:
-    """Begin a ``jax.profiler`` device trace alongside the host spans.
-    Raises whatever the profiler raises when it cannot start."""
-    import jax.profiler
-    jax.profiler.start_trace(log_dir)
-
-
-def stop_device_trace() -> None:
-    import jax.profiler
-    jax.profiler.stop_trace()

@@ -20,8 +20,9 @@ from repro.core.astraea import AstraeaTrainer
 from repro.launch.mesh import make_mediator_mesh
 from repro.launch.metrics_endpoint import CONTENT_TYPE, MetricsServer
 from repro.models.cnn import count_params, emnist_cnn
+from repro.core import scheduling
 from repro.obs import (NULL_TELEMETRY, SCHEMA_VERSION, Telemetry, Tracer,
-                       load_jsonl, validate_events)
+                       host_read, load_jsonl, validate_events)
 from repro.obs.metrics import Counter, Histogram, MetricsRegistry
 from repro.optim import adam
 
@@ -123,16 +124,59 @@ def test_jsonl_schema_and_nesting(traced_sync):
     assert events and all(e["schema"] == SCHEMA_VERSION for e in events)
     names = {e["name"] for e in events}
     assert {"round", "pack", "reschedule", "store_stream",
-            "aggregate"} <= names
+            "round_program"} <= names
     # one round span per round, each a root (no parent)
     rounds = [e for e in events if e["name"] == "round"]
     assert len(rounds) == 3
     assert all(e["parent"] is None for e in rounds)
-    # pack/aggregate spans nest under a round span
+    # pack/round_program spans nest under a round span
     rids = {e["id"] for e in rounds}
     for e in events:
-        if e["name"] in ("pack", "aggregate"):
+        if e["name"] in ("pack", "round_program"):
             assert e["parent"] in rids
+
+
+def test_round_path_spans_and_host_reads(traced_sync):
+    """The sync round's span tree, and its device->host reads: the Alg. 3
+    picks under ``reschedule``, one Fig. 7 KLD per mediator under
+    ``schedule_stats``. Each round span counts them, and the registry
+    mirrors the total."""
+    _, tel, _ = traced_sync
+    events = tel.tracer.events
+    by_id = {e["id"]: e for e in events}
+    m = math.ceil(C / GAMMA)
+    # the trainer reschedules in its first round only
+    for name, parent, n in [("round_keys", "round", 3),
+                            ("round_program", "round", 3),
+                            ("reschedule", "round", 1), ("pack", "round", 1),
+                            ("host_read.alg3_picks", "reschedule", 1),
+                            ("schedule_stats", "reschedule", 1),
+                            ("host_read.kld", "schedule_stats", m),
+                            ("store_stream", "pack", 1)]:
+        got = [by_id[e["parent"]]["name"] for e in events
+               if e["name"] == name]
+        assert got == [parent] * n, name
+    rounds = [e for e in events if e["name"] == "round"]
+    assert [e["attrs"]["host_reads"] for e in rounds] == [1 + m, 0, 0]
+    assert tel.host_reads == 1 + m
+    assert tel.metrics.snapshot()["astraea_host_reads_total"] == 1 + m
+
+
+def test_host_read_is_the_plain_read_counted_when_recording():
+    counts = np.random.default_rng(0).integers(0, 9, (8, 5)).astype(float)
+    tel = Telemetry()
+    x = jax.numpy.arange(4.0) / 3
+    np.testing.assert_array_equal(host_read(tel, "x", np.asarray, x),
+                                  np.asarray(x))
+    assert host_read(None, "x", float, x[1]) == float(x[1])
+    assert tel.host_reads == 1 and NULL_TELEMETRY.host_reads == 0
+    # the loop oracle reads one score vector per greedy step
+    loop = scheduling.reschedule(counts, 3, impl="loop", telemetry=tel)
+    assert [m.clients for m in loop] == [
+        m.clients for m in scheduling.reschedule(counts, 3, impl="loop")]
+    assert tel.host_reads == 1 + 8
+    assert [e["name"] for e in tel.tracer.events].count(
+        "host_read.alg3_scores") == 8
 
 
 def test_round_traces_stay_one_under_tracing(traced_sync):
