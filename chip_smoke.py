@@ -151,8 +151,9 @@ def round_text(engine) -> str:
     ``num_round_traces`` before calling this."""
     data, plan, unperm, slot, row_to_group, m_real = engine.ensure_schedule()
     keys = engine._round_keys(row_to_group, m_real)
-    lowered = engine._round_fn.lower(engine.server_state, data, plan, unperm,
-                                     slot, keys, *engine.extra_args())
+    lowered = engine._round_program.lower(engine.server_state, data, plan,
+                                          unperm, slot, keys,
+                                          *engine.extra_args())
     return lowered.compile().as_text()
 
 

@@ -16,8 +16,8 @@ Workflow (paper Fig. 3):
 
 * ``"online"`` (default) -- the plan is handed to the round engine and the
   resample+warp runs inside the jitted round program, redrawn every round
-  (``augmentation.online_augment_batch``).  No augmented copy is ever
-  materialized: client stores keep the raw federation (zero extra device
+  (``augmentation.online_draw``, ``compact_warp``).  No augmented copy is
+  ever materialized: client stores keep the raw federation (zero extra device
   storage), and Alg. 3 / Eq. 6 run on the expected post-augmentation
   histograms.  ``planned_extra_frac`` reports what the paper's Fig. 9
   storage cost *would have been*.

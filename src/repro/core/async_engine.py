@@ -100,8 +100,9 @@ WAN ledger is process-count-invariant (asserted by
 ``benchmarks/distributed_smoke.py``).
 
 Online augmentation: a wave runs the engine's row program, so the
-in-round resample+warp (``core/augmentation.online_augment_batch``) rides
-along unchanged.  The augmentation keys fork off the engine's round-indexed
+in-round resample (``core/augmentation.online_draw``) and compacted warp
+(``compact_warp``) ride along unchanged; the rows outside a wave are
+masked out, so their slots are not warped.  The augmentation keys fork off the engine's round-indexed
 ``_round_keys`` stream per mediator row -- never off wave membership -- so
 a mediator draws the same augmentations whichever wave executes it, and
 S=0 stays bitwise-identical to the synchronous engine with augmentation

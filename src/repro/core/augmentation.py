@@ -23,13 +23,16 @@ Two execution modes share the plan math:
   federation is rebuilt.  Faithful to the paper's deployment (clients store
   their augmentations, the ~24% extra-storage cost of Fig. 9) and kept as
   the equivalence oracle for the online mode.
-* **Online** (``online_augment_batch``) -- the device-resident pipeline:
-  nothing is materialized; each round the jitted round program redraws a
-  fixed-shape, class-conditional resample+warp of every scheduled client's
-  padded batch.  Each output slot draws its source sample from a seeded
-  categorical with per-sample weights ``mask * (1 + n_aug[y])`` and is then
-  warped with probability ``n_aug[y] / (1 + n_aug[y])`` -- so the expected
-  class mixture of the draws is exactly ``planned_counts`` (normalized) and
+* **Online** (``online_draw`` + ``compact_warp``) -- the device-resident
+  pipeline: nothing is materialized; each round the jitted round program
+  redraws a fixed-shape, class-conditional resample of every scheduled
+  client's padded batch, then warps the masked-in slots drawn as
+  augmented, compacted to a capacity that fits their count (the other
+  slots' content never reaches a loss).  Each output slot draws its
+  source sample from a seeded categorical with per-sample weights
+  ``mask * (1 + n_aug[y])`` and is then warped with probability
+  ``n_aug[y] / (1 + n_aug[y])`` -- so the expected class mixture of the
+  draws is exactly ``planned_counts`` (normalized) and
   the expected raw-vs-warped composition matches Alg. 2's ``C_y`` originals
   + ``C_y * n_aug_y`` copies, while every shape stays static (one round
   trace).  Stores keep the *raw* clients: per-device bytes fall back to the
@@ -209,19 +212,21 @@ def warp_params(key: Array, n: int, *, shift: float = 3.0, rot: float = 0.3,
         k, shift=shift, rot=rot, shear=shear, zoom=zoom))(keys)
 
 
-def warp_batch(key: Array, images: Array, *, impl: str = "auto",
-               order: int = 1, **kw) -> Array:
-    """One random affine warp of every image in ``(B, H, W, C)``, fused.
+def affine_warp(images: Array, mats: Array, trans: Array, *,
+                impl: str = "auto", order: int = 1) -> Array:
+    """Warp every image of ``(B, H, W, C)`` by its own inverse affine
+    ``mats (B, 2, 2)`` / ``trans (B, 2)``.
 
     ``impl`` picks the resampler: ``"reference"`` is the vectorized
     ``map_coordinates`` oracle (``kernels/ref.py``), ``"pallas"`` the fused
     one-launch bilinear-warp kernel (``kernels/affine_warp.py``,
     interpret-mode off-TPU), ``"auto"`` resolves to the kernel on TPU and
     the reference elsewhere (interpret-mode Pallas in a hot CPU round loop
-    would be strictly slower than XLA's fused gather).
+    would be strictly slower than XLA's fused gather). Both warp each
+    image on its own, so an image's result does not depend on the batch
+    it is warped in.
     """
     from repro.kernels import ref as kref
-    mats, trans = warp_params(key, images.shape[0], **kw)
     if impl == "auto":
         impl = "pallas" if jax.default_backend() == "tpu" else "reference"
     if impl == "pallas":
@@ -235,10 +240,97 @@ def warp_batch(key: Array, images: Array, *, impl: str = "auto",
     return kref.affine_warp(images, mats, trans, order=order)
 
 
-def online_augment_batch(key: Array, x: Array, y: Array, mask: Array,
-                         plan: Array, *, impl: str = "auto", order: int = 1,
-                         **kw) -> tuple[Array, Array]:
-    """Fixed-shape class-conditional resample+warp of one padded client batch.
+def warp_batch(key: Array, images: Array, *, impl: str = "auto",
+               order: int = 1, **kw) -> Array:
+    """One random affine warp of every image in ``(B, H, W, C)``, fused
+    (``affine_warp`` over ``warp_params(key, B)``)."""
+    mats, trans = warp_params(key, images.shape[0], **kw)
+    return affine_warp(images, mats, trans, impl=impl, order=order)
+
+
+#: smallest capacity of the compacted warp; each rung above is 4x the last
+LADDER_BASE = 1024
+
+
+def warp_ladder(n: int) -> tuple[int, ...]:
+    """The capacities ``compact_warp`` chooses among for ``n`` slots: the
+    powers of 4 from ``LADDER_BASE`` below ``n``, then ``n`` itself, so no
+    round can overflow the top rung."""
+    rungs = []
+    cap = LADDER_BASE
+    while cap < n:
+        rungs.append(cap)
+        cap *= 4
+    return (*rungs, n)
+
+
+def compact_warp(images: Array, mats: Array, trans: Array, flags: Array, *,
+                 impl: str = "auto", order: int = 1
+                 ) -> tuple[Array, Array, Array]:
+    """Warp only the flagged images of ``(N, H, W, C)``; the rest pass
+    through unchanged.
+
+    The flags are counted on the device, and ``lax.switch`` picks the
+    smallest rung of ``warp_ladder(N)`` that holds them. A rung below
+    ``N`` gathers the first ``cap`` flagged images with their
+    ``mats (N, 2, 2)`` and ``trans (N, 2)``, warps the ``cap`` of them in
+    one ``affine_warp`` call, and scatters the results back; entries past
+    the count point outside the array and drop. The top rung warps all
+    ``N`` and selects the flagged. Each flagged image is warped exactly as
+    a warp of all ``N`` would warp it, at every rung.
+
+    Returns ``(out, slots, capacity)``: the images, the number of flagged
+    slots, and the rung that warped them (int32 device scalars).
+    """
+    n = images.shape[0]
+    rungs = warp_ladder(n)
+    flags = flags.reshape(n)
+    slots = jnp.sum(flags, dtype=jnp.int32)
+    rank = jnp.cumsum(flags, dtype=jnp.int32)    # 1-based rank when flagged
+
+    def at_capacity(cap):
+        if cap == n:
+            # the top rung: warp every slot, keep the flagged ones
+            def run_all(images):
+                warped = affine_warp(images, mats, trans, impl=impl,
+                                     order=order)
+                sel = flags.reshape((n,) + (1,) * (images.ndim - 1))
+                return jnp.where(sel, warped, images)
+            return run_all
+
+        def run(images):
+            j = jnp.arange(1, cap + 1, dtype=jnp.int32)
+            # the j-th flagged slot; n once j passes the count
+            src = jnp.searchsorted(rank, j, side="left").astype(jnp.int32)
+            take = jnp.minimum(src, n - 1)
+            warped = affine_warp(images[take], mats[take], trans[take],
+                                 impl=impl, order=order)
+            # past the count: distinct out-of-range rows, dropped
+            dst = jnp.where(j <= slots, src, n + j)
+            return images.at[dst].set(warped, mode="drop",
+                                      indices_are_sorted=True,
+                                      unique_indices=True)
+        return run
+
+    branch = jnp.sum(slots > jnp.asarray(rungs[:-1], jnp.int32),
+                     dtype=jnp.int32)
+    out = jax.lax.switch(branch, [at_capacity(c) for c in rungs], images)
+    return out, slots, jnp.asarray(rungs, jnp.int32)[branch]
+
+
+class OnlineDraw(NamedTuple):
+    """One client's Alg. 2 draw (``online_draw``): what each slot trains
+    on before the warp, and the warp it gets if ``is_aug``."""
+    x: Array            # (pad, H, W, C) drawn source images
+    y: Array            # (pad,) their labels
+    is_aug: Array       # (pad,) bool: the draw is a warped copy
+    mats: Array         # (pad, 2, 2) per-slot inverse affine
+    trans: Array        # (pad, 2) per-slot translation
+
+
+def online_draw(key: Array, x: Array, y: Array, mask: Array, plan: Array,
+                **kw) -> OnlineDraw:
+    """Fixed-shape class-conditional resample of one padded client batch.
 
     ``x (pad, H, W, C)`` / ``y (pad,)`` / ``mask (pad,)`` are the client's
     packed slot tensors; ``plan (num_classes,)`` is the server's broadcast
@@ -252,11 +344,12 @@ def online_augment_batch(key: Array, x: Array, y: Array, mask: Array,
       (``online_mixture``), exactly;
     * expected warped fraction within class ``y`` == ``n_aug_y/(1+n_aug_y)``,
       matching Alg. 2's originals-plus-copies composition;
-    * shapes (and the round trace) are static; the caller's mask is
-      returned unchanged semantics-wise (an all-dummy slot stays an exact
-      no-op: all weights 0 keeps the loss mask 0 regardless of content).
+    * shapes (and the round trace) are static; the caller's mask keeps its
+      meaning (an all-dummy slot stays an exact no-op: all weights 0 keeps
+      the loss mask 0 regardless of content).
 
-    Returns ``(x_drawn, y_drawn)``; the mask is unchanged by construction.
+    The warp itself is left to ``compact_warp``, so a caller can warp the
+    slots of many clients at once, and only those it trains on.
     """
     plan_f = jnp.asarray(plan).astype(jnp.float32)
     mult = 1.0 + plan_f[y]                         # per-sample multiplicity
@@ -272,9 +365,21 @@ def online_augment_batch(key: Array, x: Array, y: Array, mask: Array,
     s_mult = 1.0 + plan_f[sy]
     p_aug = (s_mult - 1.0) / s_mult                # n_aug / (1 + n_aug)
     is_aug = jax.random.uniform(k_flag, p_aug.shape) < p_aug
-    warped = warp_batch(k_warp, sx, impl=impl, order=order, **kw)
-    sel = is_aug.reshape(is_aug.shape + (1,) * (x.ndim - 1))
-    return jnp.where(sel, warped, sx), sy
+    mats, trans = warp_params(k_warp, y.shape[0], **kw)
+    return OnlineDraw(sx, sy, is_aug, mats, trans)
+
+
+def online_augment_batch(key: Array, x: Array, y: Array, mask: Array,
+                         plan: Array, *, impl: str = "auto", order: int = 1,
+                         **kw) -> tuple[Array, Array]:
+    """One client's draw (``online_draw``) with every drawn-as-augmented
+    slot warped, masked-in or not. Returns ``(x_drawn, y_drawn)``; the
+    mask is unchanged by construction. The engine instead warps only the
+    masked-in augmented slots of the whole round (``compact_warp``)."""
+    d = online_draw(key, x, y, mask, plan, **kw)
+    out, _, _ = compact_warp(d.x, d.mats, d.trans, d.is_aug, impl=impl,
+                             order=order)
+    return out, d.y
 
 
 AUG_MODES = (None, "online", "materialized")

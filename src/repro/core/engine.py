@@ -79,10 +79,13 @@ What makes it an engine rather than a trainer loop:
    stays 1 for an async engine too.
 7. **Online rebalancing.** When the engine is built with an ``aug_plan``
    (the server's tiny ``(num_classes,)`` Alg. 2 array, broadcast once and
-   fed into the jitted round as a true operand), each mediator row's
-   per-slot data is passed through ``augmentation.online_augment_batch``
-   INSIDE the row program before training: a fixed-shape class-conditional
-   resample+warp redrawn every round from round-indexed keys.  The store
+   fed into the jitted round as a true operand), every row's per-slot
+   data is redrawn INSIDE the round program before training
+   (``augmentation.online_draw``: a fixed-shape class-conditional resample
+   from round-indexed keys), then the masked-in slots drawn as augmented
+   are warped in one ``augmentation.compact_warp`` over all the device's
+   slots, at the smallest capacity of a fixed ladder that holds them
+   (``last_warp_slots`` / ``last_warp_capacity``).  The store
    keeps the *raw* clients (per-device bytes stay at the pre-augmentation
    packed size under every placement policy), Alg. 3 schedules on the
    expected post-augmentation histograms ``counts * (1 + plan)``, and the
@@ -528,7 +531,12 @@ class FLRoundEngine:
         self._schedule: tuple | None = None
         self._round = 0
         self._wave_fns: dict[int, Callable] = {}    # width -> sliced wave_fn
-        self._round_fn = self._build_round_fn(loss_fn)
+        # the last sync round's online Alg. 2 warp: slots warped and the
+        # capacity that warped them, int32 device scalars the round path
+        # never reads (None until an augmented sync round has run)
+        self.last_warp_slots: jax.Array | None = None
+        self.last_warp_capacity: jax.Array | None = None
+        self._round_program = self._build_round_fn(loss_fn)
 
     # ------------------------------------------------------------------
     # round program
@@ -662,31 +670,57 @@ class FLRoundEngine:
         use_aug = self._aug_plan is not None
         n_aug = 1 if use_aug else 0
 
-        def _rows(fn, params, *batched):
+        def _each_row(fn, *batched):
             if cfg.row_exec == "map":
-                return jax.lax.map(lambda args: fn(params, *args), batched)
-            return jax.vmap(fn, in_axes=(None,) + (0,) * len(batched))(
-                params, *batched)
+                return jax.lax.map(lambda args: fn(*args), batched)
+            return jax.vmap(fn)(*batched)
 
-        def _augment(key, x, y, m, aplan):
-            with jax.named_scope("alg2_warp"):
-                return augmentation.online_augment_batch(key, x, y, m, aplan,
-                                                         impl=cfg.warp_impl)
+        def _rows(fn, params, *batched):
+            return _each_row(lambda *row: fn(params, *row), *batched)
 
-        if self._tp_rows:
-            # under TP rows the model axis stays compiler-auto, and XLA
-            # cannot partition a Mosaic kernel (the warp): run Alg. 2
-            # manual over it -- every model column augments the same
-            # replicated client slot (the mesh is the enclosing region's)
-            _augment = jax.shard_map(_augment, in_specs=P(), out_specs=P(),
+        def _augment(draw_row, keys, x, y, m, aplan):
+            # Alg. 2 for every row this device trains: draw each row's
+            # slots (row by row, as the rows train), then ONE compacted
+            # warp of the masked-in slots drawn as augmented -- a
+            # masked-out slot's loss weight is 0, so its content is never
+            # read. Under row_exec="map" this holds every row's drawn
+            # slots at once, no more than the slot gather's xs holds.
+            def body(keys, x, y, m, aplan):
+                with jax.named_scope("alg2_warp"):
+                    d = _each_row(
+                        lambda k, xr, yr, mr: draw_row(k, xr, yr, mr, aplan),
+                        keys, x, y, m)
+                    flags = d.is_aug & (m > 0)
+                    flat = (-1,) + x.shape[flags.ndim:]
+                    ax, slots, cap = augmentation.compact_warp(
+                        d.x.reshape(flat), d.mats.reshape(-1, 2, 2),
+                        d.trans.reshape(-1, 2), flags, impl=cfg.warp_impl)
+                    return ax.reshape(x.shape), d.y, slots, cap
+
+            if self._tp_rows:
+                # under TP rows the model axis stays compiler-auto, and XLA
+                # cannot partition a Mosaic kernel (the warp): run Alg. 2
+                # manual over it -- every model column augments the same
+                # replicated client slots (the mesh is the enclosing
+                # region's)
+                body = jax.shard_map(body, in_specs=P(), out_specs=P(),
                                      axis_names={"model"}, check_vma=False)
+            return body(keys, x, y, m, aplan)
 
-        def _aug_one(key, x, y, m, aplan):
-            # the augmentation stream forks off the row's round key with a
-            # salt, leaving the training stream (split from the same key
-            # inside the update) untouched
-            return _augment(jax.random.fold_in(key, augmentation.AUG_SALT),
-                            x, y, m, aplan)
+        # the augmentation stream forks off the row's round key with a
+        # salt, leaving the training stream (split from the same key
+        # inside the update) untouched; a mediator row splits it once
+        # more, one key per client
+        def _draw_client(k, x, y, m, aplan):
+            return augmentation.online_draw(
+                jax.random.fold_in(k, augmentation.AUG_SALT), x, y, m, aplan)
+
+        def _draw_mediator(k, x, y, m, aplan):
+            aks = jax.random.split(
+                jax.random.fold_in(k, augmentation.AUG_SALT), x.shape[0])
+            return jax.vmap(
+                lambda kk, xc, yc, mc: augmentation.online_draw(
+                    kk, xc, yc, mc, aplan))(aks, x, y, m)
 
         def _train(state, data, plan, slot, keys, *extra):
             # plan/slot/keys arrive as this device's (M_local, ...) shards;
@@ -696,6 +730,8 @@ class FLRoundEngine:
             # (backbone, a_tree) pair when a mapping is installed -- in
             # which case arg-0 `state` is the flat adapter dict and the
             # update closures train it through the merged-apply model.
+            # Returns the rows' outputs, their Eq. 6 weights, and with
+            # Alg. 2 the device's warp count and capacity as (1,) arrays.
             aug = extra[:n_aug]
             if lora_on:
                 backbone, a_tree = extra[n_aug:]
@@ -716,36 +752,24 @@ class FLRoundEngine:
             with jax.named_scope("slot_gather"):
                 xs, ys, ms_raw = store.slot_data(data, plan)
             if parallel_clients:
+                xs, ys = xs[:, 0], ys[:, 0]
                 ms = ms_raw[:, 0] * slot[:, :1]
-                row_fn = local_train
-                weights = ms.sum(axis=1)
-                if use_aug:
-                    (aplan,) = aug
-                    def row_fn(p, x, y, m, k):           # noqa: F811
-                        ax, ay = _aug_one(k, x, y, m, aplan)
-                        return local_train(p, ax, ay, m, k)
-                    # Eq. 6 over the expected post-augmentation sizes
-                    weights = (ms * (1.0 + aplan.astype(jnp.float32)[ys[:, 0]])
-                               ).sum(axis=1)
-                outs = _rows(row_fn, state, xs[:, 0], ys[:, 0], ms, keys)
-                return outs, weights
-            ms = ms_raw * slot[..., None]
-            row_fn = local_train
-            weights = ms.sum(axis=(1, 2))
+                draw_row, slot_axes = _draw_client, (1,)
+            else:
+                ms = ms_raw * slot[..., None]
+                draw_row, slot_axes = _draw_mediator, (1, 2)
+            weights = ms.sum(axis=slot_axes)
+            warp = ()
             if use_aug:
                 (aplan,) = aug
-                def row_fn(p, xr, yr, mr, k):            # noqa: F811
-                    aks = jax.random.split(
-                        jax.random.fold_in(k, augmentation.AUG_SALT),
-                        xr.shape[0])
-                    ax, ay = jax.vmap(
-                        lambda kk, x, y, m: _augment(kk, x, y, m, aplan)
-                    )(aks, xr, yr, mr)
-                    return local_train(p, ax, ay, mr, k)
+                # Eq. 6 over the expected post-augmentation sizes
                 weights = (ms * (1.0 + aplan.astype(jnp.float32)[ys])
-                           ).sum(axis=(1, 2))
-            outs = _rows(row_fn, state, xs, ys, ms, keys)
-            return outs, weights
+                           ).sum(axis=slot_axes)
+                xs, ys, slots, cap = _augment(draw_row, keys, xs, ys, ms,
+                                              aplan)
+                warp = (slots[None], cap[None])
+            outs = _rows(local_train, state, xs, ys, ms, keys)
+            return outs, weights, warp
 
         aug_specs = (P(),) if use_aug else ()
         # LoRA trailing operands: backbone + frozen A, replicated over the
@@ -764,11 +788,15 @@ class FLRoundEngine:
             _train, self.mesh,
             in_specs=(P(), store.data_specs, store.plan_specs,
                       P_med, P_med) + aug_specs + lora_specs,
-            out_specs=(P_med, P_med),
+            out_specs=(P_med, P_med, (P_med, P_med) if use_aug else ()),
             manual_axes=manual)
 
         def trained_rows(state, data, plan, unperm, slot, keys, *extra):
-            stacked, weights = train(state, data, plan, slot, keys, *extra)
+            # -> (stacked, weights, warp); warp is () without Alg. 2, else
+            # the round's warped-slot count and the summed capacity
+            stacked, weights, warp = train(state, data, plan, slot, keys,
+                                           *extra)
+            warp = tuple(w.sum() for w in warp)
             if store.permutes_rows:             # undo locality placement
                 stacked = jax.tree.map(lambda a: a[unperm], stacked)
                 weights = weights[unperm]
@@ -777,7 +805,7 @@ class FLRoundEngine:
             rep = replicated_sharding(self.mesh)
             stacked = jax.lax.with_sharding_constraint(stacked, rep)
             weights = jax.lax.with_sharding_constraint(weights, rep)
-            return stacked, weights
+            return stacked, weights, warp
 
         def _prep(state, extra):
             # the pre-shard_map gathers of the gather oracle: replicate
@@ -796,12 +824,12 @@ class FLRoundEngine:
         def round_fn(state, data, plan, unperm, slot, keys, *extra):
             self._note_trace("round_fn")        # python: counts (re)traces
             state, extra = _prep(state, extra)          # §8: model gather
-            stacked, weights = trained_rows(state, data, plan, unperm, slot,
-                                            keys, *extra)
+            stacked, weights, warp = trained_rows(state, data, plan, unperm,
+                                                  slot, keys, *extra)
             with jax.named_scope("eq6"):
                 agg = self._aggregate(stacked, weights)
             with jax.named_scope("fold"):
-                return self._fold(state, agg)
+                return self._fold(state, agg), warp
 
         def wave_fn(state, data, plan, unperm, slot, keys, *extra):
             # the wave-partitioned entry point (core/async_engine.py): the
@@ -812,7 +840,8 @@ class FLRoundEngine:
             # snapshot state is shared by all waves of a round.
             self._note_trace("wave_fn")         # python: counts (re)traces
             state, extra = _prep(state, extra)          # §8: model gather
-            return trained_rows(state, data, plan, unperm, slot, keys, *extra)
+            return trained_rows(state, data, plan, unperm, slot, keys,
+                                *extra)[:2]
 
         self.wave_fn = jax.jit(wave_fn)
 
@@ -830,13 +859,21 @@ class FLRoundEngine:
                 self._note_trace(tag)       # python: counts (re)traces
                 state, extra = _prep(state, extra)      # §8: model gather
                 return trained_rows(state, data, plan, unperm, slot, keys,
-                                    *extra)
+                                    *extra)[:2]
 
             return jax.jit(sliced)
 
         self._make_wave_fn = make_sliced_wave_fn
         donate = (0,) if cfg.donate_params else ()
         return jax.jit(round_fn, donate_argnums=donate)
+
+    def _round_fn(self, state, *args) -> PyTree:
+        """One sync round program: the new server state, keeping the
+        round's warp count and capacity as ``last_warp_*``."""
+        state, warp = self._round_program(state, *args)
+        if warp:
+            self.last_warp_slots, self.last_warp_capacity = warp
+        return state
 
     def wave_fn_for(self, m_rows: int) -> Callable:
         """The sliced wave executable over ``m_rows`` schedule rows.

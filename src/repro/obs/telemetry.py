@@ -174,6 +174,15 @@ class Telemetry:
         m.counter("astraea_host_reads_total",
                   "device->host reads on the round path (obs.host_read)"
                   ).set_total(self.host_reads)
+        if engine.last_warp_slots is not None:
+            # read after the round span closed, from a program the
+            # round_program span already waited for: not a round-path read
+            m.gauge("astraea_warp_slots",
+                    "slots the last sync round's online Alg. 2 warped"
+                    ).set(int(engine.last_warp_slots))
+            m.gauge("astraea_warp_capacity",
+                    "capacity of the compacted warp that warped them"
+                    ).set(int(engine.last_warp_capacity))
         retraces = [t for t in getattr(engine, "trace_log", [])
                     if t["reason"] != "initial"]
         m.gauge("astraea_unexpected_retraces",

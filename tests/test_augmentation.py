@@ -161,3 +161,56 @@ def test_rebalance_federation_reduces_global_kld(key, tiny_federation):
     after = float(dist.kld_to_uniform(jnp.asarray(counts)))
     assert after < before
     assert extra > 0
+
+
+def _flagged(rng, n, count):
+    """``is_aug`` and ``mask`` over ``n`` slots whose conjunction flags
+    exactly ``count`` slots, with augmented masked-out slots and masked-in
+    plain slots besides."""
+    flags = np.zeros(n, bool)
+    flags[rng.choice(n, count, replace=False)] = True
+    rest = np.flatnonzero(~flags)
+    is_aug, mask = flags.copy(), flags.copy()
+    is_aug[rest[: rest.size // 2]] = True      # augmented, masked out
+    mask[rest[rest.size // 2:]] = True         # masked in, not augmented
+    return jnp.asarray(is_aug), jnp.asarray(mask, jnp.float32)
+
+
+_N = 1100                                       # ladder (1024, 1100)
+
+
+@pytest.mark.parametrize("count", [0, 1, 1024, 1025, _N],
+                         ids=["none", "one", "rung", "rung+1", "all"])
+@pytest.mark.parametrize("h,c", [(28, 1), (32, 3)])
+def test_compact_warp_matches_full_warp(count, h, c):
+    """The compacted warp of ``is_aug & mask`` equals the full warp of
+    every slot wherever the slot is masked in, bitwise: warped where
+    flagged, the drawn image where not. It counts the flags and warps
+    them at the smallest rung that holds them."""
+    assert aug.warp_ladder(_N) == (1024, _N)
+    rng = np.random.default_rng(count)
+    sx = jnp.asarray(rng.normal(size=(_N, h, h, c)).astype(np.float32))
+    mats, trans = aug.warp_params(jax.random.PRNGKey(count), _N)
+    is_aug, mask = _flagged(rng, _N, count)
+    flags = is_aug & (mask > 0)
+    out, slots, cap = jax.jit(
+        lambda *a: aug.compact_warp(*a, impl="reference"))(sx, mats, trans,
+                                                           flags)
+    want = jax.jit(lambda sx, mats, trans, flags: jnp.where(
+        flags[:, None, None, None],
+        aug.affine_warp(sx, mats, trans, impl="reference"), sx))(
+            sx, mats, trans, flags)
+    live = np.asarray(mask) > 0
+    out, want, sx = np.asarray(out), np.asarray(want), np.asarray(sx)
+    np.testing.assert_array_equal(out[live], want[live])
+    # masked-out slots keep the drawn image instead of a warped copy
+    np.testing.assert_array_equal(out[~live], sx[~live])
+    assert int(slots) == count
+    assert int(cap) == (1024 if count <= 1024 else _N)
+
+
+def test_warp_ladder_rungs():
+    """Powers of 4 from 1,024 below the slot count, then the count."""
+    assert aug.warp_ladder(135_000) == (1024, 4096, 16384, 65536, 135_000)
+    assert aug.warp_ladder(1024) == (1024,)
+    assert aug.warp_ladder(300) == (300,)

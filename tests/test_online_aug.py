@@ -318,6 +318,42 @@ def test_eq6_weights_are_expected_post_aug_sizes(model, tiny_federation):
     assert np.all(weights[np.asarray(row_to_group) < 0] == 0)  # dummy rows
 
 
+def _fedavg_trainer(model, fed):
+    return FedAvgTrainer(model, adam(1e-3), fed, clients_per_round=4,
+                         local=LocalSpec(10, 1), alpha=0.67, seed=0,
+                         mesh=make_mediator_mesh(1))
+
+
+@pytest.mark.parametrize("make", [_trainer, _fedavg_trainer],
+                         ids=["astraea", "fedavg"])
+def test_compacted_warp_keeps_the_trajectory(model, tiny_federation,
+                                             monkeypatch, make):
+    """Warping only the masked-in augmented slots, at a rung below the
+    slot count, trains exactly as warping at the top rung, which does a
+    full warp's work: the parameters after 2 rounds are bitwise equal,
+    through the mediator chain and the parallel-clients (FedAvg + Alg. 2)
+    path, and each round program is traced once."""
+    runs = {}
+    for ladder in ("full", "compacted"):
+        with monkeypatch.context() as mp:
+            if ladder == "full":
+                mp.setattr(augmentation, "warp_ladder", lambda n: (n,))
+            else:   # small rungs, so this tiny federation climbs a ladder
+                mp.setattr(augmentation, "LADDER_BASE", 8)
+            tr = make(model, tiny_federation)
+            tr.run_round()
+            tr.run_round()
+        eng = tr.engine
+        assert eng.num_round_traces == 1
+        runs[ladder] = (tr.params, int(eng.last_warp_slots),
+                        int(eng.last_warp_capacity))
+    (full, n_full, cap_full), (comp, n_comp, cap_comp) = \
+        runs["full"], runs["compacted"]
+    _params_equal(full, comp)
+    assert n_full == n_comp > 0
+    assert n_comp <= cap_comp < cap_full
+
+
 _MULTI_DEVICE_SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"

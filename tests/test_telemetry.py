@@ -179,6 +179,65 @@ def test_host_read_is_the_plain_read_counted_when_recording():
         "host_read.alg3_scores") == 8
 
 
+def _host_warp_count(eng, round_idx):
+    """Masked-in slots drawn as augmented in round ``round_idx`` of the
+    engine's held schedule, redrawn outside the round program."""
+    from repro.core import augmentation
+    data_args, plan_args, _, slot, row_to_group, m_real = eng._schedule
+    xs, ys, ms = eng.store.slot_data(data_args, plan_args)
+    ms = ms * slot[..., None]
+    keys = eng._round_keys(row_to_group, m_real, round_idx)
+    total = 0
+    for r in range(xs.shape[0]):
+        aks = jax.random.split(
+            jax.random.fold_in(keys[r], augmentation.AUG_SALT), xs.shape[1])
+        for c in range(xs.shape[1]):
+            d = augmentation.online_draw(aks[c], xs[r, c], ys[r, c],
+                                         ms[r, c], eng._aug_plan)
+            total += int(np.sum(np.asarray(d.is_aug)
+                                & (np.asarray(ms[r, c]) > 0)))
+    return total
+
+
+def test_warp_gauges_publish_the_rounds_warp(model, tiny_federation):
+    """A recording Telemetry publishes the round's warped-slot count and
+    the capacity that warped them; the count is the host's recount of
+    ``is_aug & mask``. Reading them adds no round-path host read."""
+    def run(alpha, telemetry):
+        tr = AstraeaTrainer(model, adam(1e-3), tiny_federation,
+                            clients_per_round=C, gamma=GAMMA,
+                            local=LocalSpec(10, EM), alpha=alpha, seed=0,
+                            mesh=make_mediator_mesh(1), telemetry=telemetry)
+        seen = []
+        for r in range(2):
+            tr.run_round()
+            snap = telemetry.metrics.snapshot()
+            seen.append((snap.get("astraea_warp_slots"),
+                         snap.get("astraea_warp_capacity")))
+            if alpha is not None:
+                assert seen[-1][0] == _host_warp_count(tr.engine, r)
+        reads = [e["attrs"]["host_reads"] for e in telemetry.tracer.events
+                 if e["name"] == "round"]
+        return seen, reads
+
+    seen, reads = run(0.67, Telemetry())
+    assert all(0 < slots <= cap for slots, cap in seen)
+    seen_off, reads_off = run(None, Telemetry())
+    assert seen_off == [(None, None)] * 2       # no Alg. 2, no gauges
+    assert reads == reads_off
+
+
+def test_null_telemetry_never_reads_the_warp_count():
+    class Engine:
+        @property
+        def last_warp_slots(self):
+            raise AssertionError("NullTelemetry read the warp count")
+
+        last_warp_capacity = last_warp_slots
+
+    assert NULL_TELEMETRY.observe_round(Engine(), duration_s=1.0) is None
+
+
 def test_round_traces_stay_one_under_tracing(traced_sync):
     tr, _, _ = traced_sync
     assert tr.engine.num_round_traces == 1

@@ -68,6 +68,24 @@ def test_affine_warp_compiles(one_chip, h, c):
                    _s(one_chip, (b, 2)))
 
 
+@pytest.mark.parametrize("h,c", [(28, 1), (32, 3)])
+def test_compact_warp_compiles(one_chip, monkeypatch, h, c):
+    """The engine's compacted warp: a ``lax.switch`` over the ladder's
+    rungs, one Mosaic kernel each."""
+    from repro.core import augmentation
+    from repro.kernels import ops
+    n = 5000                                    # rungs 1024, 4096, 5000
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    text = jax.jit(
+        lambda i, m, t, f: augmentation.compact_warp(i, m, t, f,
+                                                     impl="pallas")
+    ).lower(_s(one_chip, (n, h, h, c)), _s(one_chip, (n, 2, 2)),
+            _s(one_chip, (n, 2)), _s(one_chip, (n,), jnp.bool_)
+            ).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == \
+        len(augmentation.warp_ladder(n)) == 3
+
+
 @pytest.mark.parametrize("n", [EMNIST_PARAMS, CINIC_PARAMS])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_fedavg_agg_compiles(one_chip, n, dtype):
