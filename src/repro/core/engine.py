@@ -48,7 +48,15 @@ What makes it an engine rather than a trainer loop:
    host) footprint fixed by ``clients_per_round``, never by K.
 
    A schedule is a tiny ``(M, gamma)`` int32 gather index plus a 0/1 slot
-   mask; ``run_round`` never rebuilds host buffers (the old trainers
+   mask. When rescheduling every round, ``run_round`` builds the next
+   round's schedule one round ahead: it draws that cohort and enqueues
+   its Alg. 3 and Fig. 7 in one device launch before dispatching the
+   current round, and reads them once the current round is queued, so the
+   read waits on the round before while the chip keeps working. The
+   cohort order, the schedules and the trajectory are those of building
+   each schedule at its round's start (``num_schedules_ahead`` counts the
+   rounds whose schedule was ready). ``run_round`` never rebuilds host
+   buffers (the old trainers
    re-packed ``(M, gamma, pad, ...)`` on the host every round). Slot-mask
    zeros make empty client slots exact no-ops (masked loss is 0 => zero
    grads => zero Adam updates), so a dummy slot may harmlessly gather any
@@ -257,6 +265,20 @@ def psum_eq6(delta: PyTree, n_m, mediator_axes: tuple) -> PyTree:
     return jax.tree.map(lambda d: d / den, num)
 
 
+@dataclass
+class _CohortSchedule:
+    """One round's schedule on its way to the round program: the cohort,
+    the adaptive Alg. 2 plan it implies (``None`` without one), its Alg. 3
+    and Fig. 7 launch (``None`` for FedAvg's singletons) and, once read,
+    its client groups and Fig. 7 statistics."""
+    round: int
+    sel: np.ndarray
+    plan: np.ndarray | None
+    pending: scheduling.PendingSchedule | None
+    groups: list[list[int]] | None = None
+    stats: dict | None = None
+
+
 def _per_device_param_bytes(params: PyTree) -> int:
     """Bytes of parameter residency on one device (the first addressable
     one): full leaf bytes when replicated, ``1/model`` when sharded."""
@@ -439,6 +461,9 @@ class FLRoundEngine:
         # pre-drawn future selections, oldest first (ensure_schedule keeps
         # this filled to the store's prefetch depth)
         self._pending_sels: deque = deque()
+        # the next round's schedule, launched and read by run_round while
+        # the round before it runs (None until then)
+        self._ahead: _CohortSchedule | None = None
 
         # ---- params: model-axis sharded at rest, replicated otherwise ----
         # On a 2-D mesh each device holds 1/model of every rule-table-
@@ -523,6 +548,7 @@ class FLRoundEngine:
         # client-level straggler model derives durations from membership
         self.last_groups: list[list[int]] | None = None
         self.num_schedule_packs = 0             # host packing events (bench)
+        self.num_schedules_ahead = 0            # rounds whose schedule was ready
         self.num_round_traces = 0               # round_fn (re)compilations
         # one entry per (re)trace with its *reason* -- "initial" for each
         # entry point's first compile, "retrace" for anything after; the
@@ -933,31 +959,68 @@ class FLRoundEngine:
     # ------------------------------------------------------------------
     # scheduling (host side: tiny integer work, no sample movement)
     # ------------------------------------------------------------------
-    def _groups_for(self, sel: np.ndarray) -> list[list[int]]:
+    def _next_selection(self) -> np.ndarray:
+        """The next cohort to schedule: the oldest pre-drawn selection, else
+        a fresh draw. Cohorts are consumed in draw order, so round r's is
+        always the (r+1)-th ``choice`` call however early it was drawn."""
+        if self._pending_sels:
+            return self._pending_sels.popleft()
+        c = min(self.cfg.clients_per_round, self.data.num_clients)
+        return self._rng.choice(self.data.num_clients, size=c, replace=False)
+
+    def _launch_schedule(self, sel: np.ndarray, round_idx: int,
+                         read: bool = False) -> _CohortSchedule:
+        """Start round ``round_idx``'s schedule for cohort ``sel``: the
+        adaptive plan it implies, and Alg. 3 with the Fig. 7 statistics
+        enqueued on the device as one launch, left unread unless ``read``
+        (a schedule built on the spot). Changes no engine state, so it may
+        run while an earlier round is in flight.
+
+        With ``adaptive_aug_alpha`` the plan is recomputed from the
+        *selected cohort's* label histograms (the drifted view of the
+        federation this round trains on) and Alg. 3 packs by the expected
+        post-augmentation counts it gives; ``_pack_schedule`` installs and
+        re-broadcasts it when its round runs."""
         cfg = self.cfg
-        if cfg.schedule == "kld":
-            meds = scheduling.reschedule(self._counts[sel], cfg.gamma,
-                                         use_kernel=cfg.reschedule_kernel,
-                                         telemetry=self.telemetry)
-            self.last_schedule_stats = scheduling.schedule_stats(
-                meds, self.telemetry)
-            return [[int(sel[i]) for i in m.clients] for m in meds]
-        if cfg.schedule == "random":
-            if cfg.gamma == 1:      # FedAvg: selection order, one client each
-                self.last_schedule_stats = None
-                return [[int(k)] for k in sel]
-            meds = scheduling.random_schedule(len(sel), cfg.gamma,
-                                              self._counts[sel],
-                                              seed=cfg.seed + self._round)
-            self.last_schedule_stats = scheduling.schedule_stats(
-                meds, self.telemetry)
-            return [[int(sel[i]) for i in m.clients] for m in meds]
-        raise ValueError(f"unknown schedule {cfg.schedule!r}")
+        plan = None
+        if self._adaptive_alpha is not None:
+            plan = augmentation.augmentation_plan(
+                self._raw_counts[sel].sum(axis=0), self._adaptive_alpha)
+            counts = self._raw_counts[sel] * (1.0 + plan.astype(np.float64))
+        else:
+            counts = self._counts[sel]
+        pending = None
+        with self.telemetry.span("reschedule", cohort=len(sel),
+                                 schedule=cfg.schedule, round=round_idx):
+            if cfg.schedule == "kld":
+                pending = scheduling.launch_reschedule(
+                    counts, cfg.gamma, use_kernel=cfg.reschedule_kernel)
+            elif cfg.gamma > 1:
+                pending = scheduling.launch_random_schedule(
+                    len(sel), cfg.gamma, counts, seed=cfg.seed + round_idx)
+            sched = _CohortSchedule(round_idx, sel, plan, pending)
+            if read:
+                self._read_schedule(sched)
+        return sched
 
-    def _pack_schedule(self, sel: np.ndarray) -> tuple:
-        """Schedule -> store-routed gather plan.
+    def _read_schedule(self, sched: _CohortSchedule) -> None:
+        """Read a launched schedule's groups and Fig. 7 statistics: the
+        Alg. 3 picks and each mediator's KLD, copies of what the one launch
+        computed; nothing for FedAvg (selection order, one client each)."""
+        sel = sched.sel
+        if sched.pending is None:
+            sched.groups = [[int(k)] for k in sel]
+            return
+        meds = sched.pending.read(self.telemetry)
+        sched.groups = [[int(sel[i]) for i in m.clients] for m in meds]
+        sched.stats = scheduling.schedule_stats(meds, self.telemetry)
 
-        Packs the client groups into padded ``(M_pad, gamma)`` rows (rows
+    def _pack_schedule(self, sched: _CohortSchedule) -> tuple:
+        """Schedule -> store-routed gather plan, for the round about to run.
+
+        Installs the schedule's adaptive plan (a round operand, so no
+        re-trace; re-broadcast to the cohort on the WAN ledger), then
+        packs the client groups into padded ``(M_pad, gamma)`` rows (rows
         assigned by the store's placement pass), remaps the gather through
         the store, and precomputes ``unperm`` -- the row order that puts
         stacked outputs back in schedule order before aggregation (real
@@ -965,25 +1028,12 @@ class FLRoundEngine:
         placement bit-identical to the replicated path.
         """
         tel = self.telemetry
-        if self._adaptive_alpha is not None:
-            # per-round adaptive rebalancing: recompute the Alg. 2 plan
-            # from the *selected cohort's* label histograms (the drifted
-            # view of the federation this round trains on), re-broadcast
-            # the tiny array to the cohort, and let Alg. 3 below pack by
-            # the refreshed expected post-augmentation counts. The plan is
-            # a round operand, so no re-trace happens (asserted in tests).
-            with tel.span("plan_refresh", cohort=len(sel)):
-                plan_np = augmentation.augmentation_plan(
-                    self._raw_counts[sel].sum(axis=0), self._adaptive_alpha)
-                self._install_plan(plan_np)
-                self.comm.plan_broadcast(plan_np.size, len(sel))
-        with tel.span("reschedule", cohort=len(sel),
-                      schedule=self.cfg.schedule) as rsp:
-            groups = self._groups_for(sel)
-            if self.last_schedule_stats:
-                rsp.set(kld_mean=self.last_schedule_stats.get("kld_mean"),
-                        num_mediators=len(groups))
-        self.last_groups = groups
+        if sched.plan is not None:
+            with tel.span("plan_refresh", cohort=len(sched.sel)):
+                self._install_plan(sched.plan)
+                self.comm.plan_broadcast(sched.plan.size, len(sched.sel))
+        groups = self.last_groups = sched.groups
+        self.last_schedule_stats = sched.stats
         m_real = len(groups)
         m_pad = self.cfg.pad_mediators_to or m_real
         if m_pad < m_real:
@@ -993,6 +1043,8 @@ class FLRoundEngine:
         m_pad = _pad_multiple(m_pad, self._msize)
         with tel.span("pack", m_real=m_real, m_pad=m_pad,
                       policy=self.store.policy) as psp:
+            if sched.stats:
+                psp.set(kld_mean=sched.stats["kld_mean"])
             row_to_group = self.store.place(groups, m_pad)
             idx = np.zeros((m_pad, self.cfg.gamma), np.int32)
             slot = np.zeros((m_pad, self.cfg.gamma), np.float32)
@@ -1048,24 +1100,28 @@ class FLRoundEngine:
     def ensure_schedule(self) -> tuple:
         """(Re)pack the gather schedule if this round needs one.
 
-        With a prefetch-capable store (``spilled``) and per-round
-        rescheduling, the next rounds' selections are pre-drawn here --
-        up to the store's ``prefetch_depth`` ahead -- and staged in the
-        background, so the spill-tier reads overlap this round's device
+        A schedule that ``run_round`` built one round ahead is packed as
+        it is (``num_schedules_ahead`` counts them); otherwise it is built
+        here, on the spot. With a prefetch-capable store (``spilled``) and
+        per-round rescheduling, the next rounds' selections are pre-drawn
+        here -- up to the store's ``prefetch_depth`` ahead -- and staged in
+        the background, so the spill-tier reads overlap this round's device
         compute. The rng draws happen in the same order as the eager
         path (round r's selection is always the (r+1)-th ``choice``
-        call; depth only changes how early the calls are issued), so
-        trajectories are bitwise unchanged at any depth."""
+        call; depth and the lookahead only change how early the calls are
+        issued), so trajectories are bitwise unchanged at any depth."""
         cfg = self.cfg
-        c = min(cfg.clients_per_round, self.data.num_clients)
         if cfg.reschedule_every_round or self._schedule is None:
-            if self._pending_sels:
-                sel = self._pending_sels.popleft()
+            sched, self._ahead = self._ahead, None
+            if sched is not None and sched.round == self._round:
+                self.num_schedules_ahead += 1
             else:
-                sel = self._rng.choice(self.data.num_clients, size=c,
-                                       replace=False)
-            self._schedule = self._pack_schedule(sel)
+                sel = sched.sel if sched is not None \
+                    else self._next_selection()
+                sched = self._launch_schedule(sel, self._round, read=True)
+            self._schedule = self._pack_schedule(sched)
             if cfg.reschedule_every_round and hasattr(self.store, "prefetch"):
+                c = min(cfg.clients_per_round, self.data.num_clients)
                 depth = max(1, int(getattr(self.store, "prefetch_depth", 1)))
                 while len(self._pending_sels) < depth:
                     nxt = self._rng.choice(self.data.num_clients, size=c,
@@ -1083,6 +1139,14 @@ class FLRoundEngine:
             reads0 = tel.host_reads
             data_args, plan_args, unperm, slot, row_to_group, m_real = \
                 self.ensure_schedule()
+            ahead = None
+            if cfg.reschedule_every_round:
+                # the next round's Alg. 3 and Fig. 7 go on the device queue
+                # ahead of this round's program and are read once it is
+                # queued: the read waits on the round before this one,
+                # while this one keeps the chip busy
+                ahead = self._launch_schedule(self._next_selection(),
+                                              self._round + 1)
             with tel.span("round_keys"):
                 keys = self._round_keys(row_to_group, m_real)
             with tel.span("round_program", mediators=m_real) as psp:
@@ -1091,6 +1155,9 @@ class FLRoundEngine:
                                                    unperm, slot, keys,
                                                    *self.extra_args())
                 psp.sync_on(self.server_state)
+            if ahead is not None:
+                self._read_schedule(ahead)
+                self._ahead = ahead
             if cfg.aggregate == "weights":
                 self.comm.fedavg_round(c)
             else:

@@ -13,7 +13,12 @@ Two implementations, identical mediator lists:
   reschedule. The historical per-step dispatch (one
   ``merged_kld_scores`` call + host argmin per absorbed client) cost
   O(K) roundtrips — O(K^2) score work issued from the interpreter — and
-  stalled Alg. 3 for minutes at K=1e5. With ``use_kernel=True`` the
+  stalled Alg. 3 for minutes at K=1e5. The same launch sums each
+  mediator's histogram from the picks and scores it for Fig. 7
+  (``alg3_fig7``), so picks and statistics come from one launch;
+  ``launch_reschedule`` enqueues it and leaves the reads for later, which
+  lets the engine build a round's schedule while the chip runs the round
+  before. With ``use_kernel=True`` the
   whole pass instead runs as ONE Pallas launch
   (``kernels.kld_greedy_picks``): the masked-argmin sweep, pick commit,
   histogram fold and gamma-reset all live in kernel scratch, so a
@@ -38,6 +43,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass, field
 from functools import partial
+from typing import Any
 
 import numpy as np
 
@@ -50,13 +56,17 @@ from repro.obs.telemetry import as_telemetry, host_read
 
 @dataclass
 class Mediator:
-    """One mediator's schedule: ordered client ids + merged label counts."""
+    """One mediator's schedule: ordered client ids + merged label counts,
+    and, when its Alg. 3 launch computed it, its Fig. 7 ``D_KL(P_m ||
+    P_u)`` still on the device (``kld``)."""
     clients: list[int] = field(default_factory=list)
     counts: np.ndarray | None = None
+    kld: jax.Array | None = None
 
     def kld_to_uniform(self, telemetry=None) -> float:
-        return host_read(telemetry, "kld", float,
-                         dist.kld_to_uniform(jnp.asarray(self.counts)))
+        kld = self.kld if self.kld is not None \
+            else dist.kld_to_uniform(jnp.asarray(self.counts))
+        return host_read(telemetry, "kld", float, kld)
 
 
 def _score_candidates(mediator_counts: np.ndarray, candidate_counts: np.ndarray,
@@ -98,6 +108,90 @@ def _greedy_picks(counts_f32: jnp.ndarray, gamma: int) -> jnp.ndarray:
     return jax.lax.scan(step, init, None, length=k)[1]
 
 
+def _mediator_klds(counts_f32: jnp.ndarray, picks: jnp.ndarray,
+                   gamma: int) -> jnp.ndarray:
+    """Fig. 7 on the device: ``D_KL(P_m || P_u)`` of every mediator, its
+    histogram summed from its clients' (mediator ``s`` holds
+    ``picks[s * gamma:(s + 1) * gamma]``; a short last one is padded with
+    a zero row)."""
+    k, c = counts_f32.shape
+    m = -(-k // gamma)
+    rows = jnp.concatenate([counts_f32, jnp.zeros((1, c), jnp.float32)])
+    idx = jnp.concatenate([picks.astype(jnp.int32),
+                           jnp.full((m * gamma - k,), k, jnp.int32)])
+    return dist.kld_to_uniform(rows[idx].reshape(m, gamma, c).sum(axis=1))
+
+
+def alg3_fig7(counts_f32: jnp.ndarray, gamma: int,
+              use_kernel: bool = False) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Alg. 3 and its Fig. 7 statistics as one program: the absorption
+    order (the ``lax.scan``, or the one-launch ``kld_greedy_picks`` with
+    ``use_kernel``) and each mediator's KLD to uniform."""
+    if use_kernel:
+        from repro.kernels import ops as kops
+        picks = kops.kld_greedy_picks(counts_f32, gamma)
+    else:
+        picks = _greedy_picks(counts_f32, gamma)
+    return picks, _mediator_klds(counts_f32, picks, gamma)
+
+
+# Each mediator's KLD leaves the program as a buffer of its own, so each
+# ``host_read.kld`` is a plain copy of a computed value and launches nothing.
+@partial(jax.jit, static_argnames=("gamma", "use_kernel"))
+def _alg3_fig7(counts_f32, gamma, use_kernel):
+    picks, klds = alg3_fig7(counts_f32, gamma, use_kernel)
+    return picks, tuple(klds)
+
+
+@partial(jax.jit, static_argnames="gamma")
+def _fig7(counts_f32, order, gamma):
+    return tuple(_mediator_klds(counts_f32, order, gamma))
+
+
+@dataclass
+class PendingSchedule:
+    """A schedule on its way: the clients' order and each mediator's
+    Fig. 7 KLD, computed by one device launch and not read yet."""
+    counts: np.ndarray              # (K, C) histograms it was launched on
+    gamma: int
+    picks: Any                      # (K,) order: device (Alg. 3) or host
+    klds: tuple                     # one device scalar per mediator
+
+    def read(self, telemetry=None) -> list[Mediator]:
+        """The mediators, their KLDs left on the device for
+        ``schedule_stats``. Reads the Alg. 3 picks (``host_read.
+        alg3_picks``); a host-drawn order needs no read."""
+        picks = self.picks
+        if isinstance(picks, jax.Array):
+            picks = host_read(telemetry, "alg3_picks", np.asarray, picks)
+        g = self.gamma
+        return [Mediator(clients=[int(c) for c in picks[s:s + g]],
+                         counts=self.counts[picks[s:s + g]].sum(0), kld=kld)
+                for kld, s in zip(self.klds, range(0, len(picks), g))]
+
+
+def launch_reschedule(client_counts: np.ndarray, gamma: int, *,
+                      use_kernel: bool = False) -> PendingSchedule:
+    """Alg. 3 with the Fig. 7 statistics of its mediators, enqueued on
+    the device as one launch and left unread (``impl="batched"``)."""
+    client_counts = np.asarray(client_counts, np.float64)
+    picks, klds = _alg3_fig7(jnp.asarray(client_counts, jnp.float32),
+                             gamma=int(gamma), use_kernel=use_kernel)
+    return PendingSchedule(client_counts, int(gamma), picks, klds)
+
+
+def launch_random_schedule(num_clients: int, gamma: int,
+                           client_counts: np.ndarray,
+                           seed: int = 0) -> PendingSchedule:
+    """``random_schedule``'s grouping, drawn on the host, with the Fig. 7
+    statistics of its mediators enqueued on the device and left unread."""
+    client_counts = np.asarray(client_counts, np.float64)
+    order = np.random.default_rng(seed).permutation(num_clients)
+    klds = _fig7(jnp.asarray(client_counts, jnp.float32), jnp.asarray(order),
+                 gamma=int(gamma))
+    return PendingSchedule(client_counts, int(gamma), order, klds)
+
+
 def reschedule(client_counts: np.ndarray, gamma: int, *,
                use_kernel: bool = False, impl: str = "auto",
                telemetry=None) -> list[Mediator]:
@@ -118,7 +212,8 @@ def reschedule(client_counts: np.ndarray, gamma: int, *,
         reads (the picks, or the loop's per-step scores); off by default.
 
     Returns:
-      List of ``Mediator``; every client appears in exactly one.
+      List of ``Mediator``; every client appears in exactly one. The
+      batched path also sets each one's Fig. 7 ``kld``.
     """
     if impl not in ("auto", "batched", "loop"):
         raise ValueError(f"unknown reschedule impl {impl!r}")
@@ -129,16 +224,8 @@ def reschedule(client_counts: np.ndarray, gamma: int, *,
     if num_clients == 0:
         return []
     if impl == "batched":
-        counts_f32 = jnp.asarray(client_counts, jnp.float32)
-        if use_kernel:
-            from repro.kernels import ops as kops
-            picks = kops.kld_greedy_picks(counts_f32, int(gamma))
-        else:
-            picks = _greedy_picks(counts_f32, int(gamma))
-        picks = host_read(telemetry, "alg3_picks", np.asarray, picks)
-        return [Mediator(clients=[int(c) for c in picks[s:s + gamma]],
-                         counts=client_counts[picks[s:s + gamma]].sum(0))
-                for s in range(0, num_clients, gamma)]
+        return launch_reschedule(client_counts, gamma,
+                                 use_kernel=use_kernel).read(telemetry)
     unassigned = list(range(num_clients))
     mediators: list[Mediator] = []
     while unassigned:
@@ -158,15 +245,8 @@ def reschedule(client_counts: np.ndarray, gamma: int, *,
 def random_schedule(num_clients: int, gamma: int, client_counts: np.ndarray,
                     seed: int = 0) -> list[Mediator]:
     """Control: arbitrary grouping (what plain FedAvg round batching does)."""
-    client_counts = np.asarray(client_counts, np.float64)
-    rng = np.random.default_rng(seed)
-    order = rng.permutation(num_clients)
-    mediators = []
-    for start in range(0, num_clients, gamma):
-        ids = [int(i) for i in order[start:start + gamma]]
-        med = Mediator(clients=ids, counts=client_counts[ids].sum(0))
-        mediators.append(med)
-    return mediators
+    return launch_random_schedule(num_clients, gamma, client_counts,
+                                  seed).read()
 
 
 def place_mediators(groups: list[list[int]], num_shards: int,
@@ -294,7 +374,9 @@ def schedule_stats(mediators: list[Mediator],
     / ``astraea_store_<key>`` gauge every round -- renaming a key here
     renames the exported metric. Each mediator's KLD is one device->host
     read (``host_read.kld``), all inside the ``schedule_stats`` span of
-    ``telemetry``.
+    ``telemetry``: a copy of the value its Alg. 3 launch computed, or, for
+    a mediator without one (the loop oracle's), a ``kld_to_uniform``
+    launch of its own.
     """
     telemetry = as_telemetry(telemetry)
     with telemetry.span("schedule_stats", mediators=len(mediators)):

@@ -174,6 +174,9 @@ class Telemetry:
         m.counter("astraea_host_reads_total",
                   "device->host reads on the round path (obs.host_read)"
                   ).set_total(self.host_reads)
+        m.counter("astraea_schedule_ahead_total",
+                  "rounds whose schedule was built one round ahead"
+                  ).set_total(engine.num_schedules_ahead)
         if engine.last_warp_slots is not None:
             # read after the round span closed, from a program the
             # round_program span already waited for: not a round-path read

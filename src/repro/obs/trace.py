@@ -3,7 +3,7 @@
 A ``Tracer`` records a tree of **spans** -- named wall-clock intervals
 with attributes -- plus zero-duration **instant** events, all on the
 host, entirely outside jit. The engine opens spans around its host-side
-phases (``round`` > ``reschedule`` > ``host_read.alg3_picks`` /
+phases (``round`` > ``reschedule``, ``host_read.alg3_picks`` /
 ``schedule_stats``, ``pack`` > ``store_stream``, ``round_keys``,
 ``round_program``; async ``wave``/``commit``) so a round's wall-clock has
 one navigable timeline instead of being smeared across ad-hoc prints and

@@ -160,6 +160,8 @@ def test_round_path_spans_and_host_reads(traced_sync):
     assert [e["attrs"]["host_reads"] for e in rounds] == [1 + m, 0, 0]
     assert tel.host_reads == 1 + m
     assert tel.metrics.snapshot()["astraea_host_reads_total"] == 1 + m
+    # it reschedules once, so no schedule is built ahead
+    assert tel.metrics.snapshot()["astraea_schedule_ahead_total"] == 0
 
 
 def test_host_read_is_the_plain_read_counted_when_recording():

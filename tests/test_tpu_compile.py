@@ -109,6 +109,26 @@ def test_kld_greedy_picks_compiles(one_chip):
                    _s(one_chip, (K, C)))
 
 
+@pytest.mark.parametrize("use_kernel", [False, True])
+def test_alg3_fig7_compiles(one_chip, monkeypatch, use_kernel):
+    """The schedule the engine builds one round ahead: Alg. 3 (the scan,
+    or the one-launch kernel) and every mediator's Fig. 7 KLD in one
+    program, at the benchmark's cohort of 50 and mediators of 5."""
+    import numpy as np
+
+    from repro.core import scheduling
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    k = 50
+    compiled = scheduling._alg3_fig7.lower(
+        _s(one_chip, (k, C)), gamma=GAMMA, use_kernel=use_kernel).compile()
+    picks, klds = compiled.out_info
+    assert picks.shape == (k,) and np.dtype(picks.dtype) == np.int32
+    assert [d.shape for d in klds] == [()] * (k // GAMMA)
+    text = compiled.as_text()
+    assert ("tpu_custom_call" in text) == use_kernel
+
+
 def test_tp_rows_round_compiles(topo, monkeypatch):
     """The Astraea round with online Alg. 2 on the 2x2 (mediator, model)
     mesh with TP rows: the model axis is compiler-auto there, and XLA
